@@ -1,34 +1,20 @@
-"""Version-portable distributed-runtime layer (the engine's only JAX surface).
+"""Distributed-runtime layer (the engine's only JAX surface).
 
 The paper (§4) argues that a scalable miner needs a "well-engineered
 communication protocol" kept *separate* from the mining logic.  This module
-is that separation for the JAX substrate: every version-sensitive JAX API the
-BSP engine depends on — `shard_map`, the SPMD collectives, mesh construction,
-simulated multi-host device counts, and compiled-artifact cost introspection —
-is wrapped here, so the superstep-phase modules (expand/steal/global_sync) and
-the launchers never import a moving target directly.
-
-Portability shims handled here:
-
-  * `shard_map` location:  `jax.shard_map` (new) -> `jax.sharding.shard_map`
-    (transitional) -> `jax.experimental.shard_map.shard_map` (old).
-  * The replication-check kwarg rename: `check_vma` (new) vs `check_rep`
-    (old).  `shard_map()` below accepts `check_replication=` and forwards to
-    whichever kwarg the resolved function actually takes.
-  * `Compiled.cost_analysis()` return type: dict (old) vs single-element
-    list-of-dict (new).  `normalize_cost_analysis()` always returns a dict.
-
-Everything else (`psum`, `ppermute`, mesh building, host device-count
-forcing) is stable across the versions we target but lives here anyway so the
-engine has exactly one import for its distributed runtime.
+is that separation for the JAX substrate: `shard_map`, the SPMD collectives,
+mesh construction, simulated host device counts, and compiled-artifact cost
+introspection live here, so the superstep-phase modules
+(expand/steal/global_sync) and the launchers have exactly one import for
+their distributed runtime.  It targets the installed JAX (0.9): `shard_map`
+is `jax.shard_map` with `check_vma`, and `Compiled.cost_analysis()` returns
+a dict.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
 import os
-from typing import Any, Callable, Sequence
+from typing import Callable, Sequence
 
 import numpy as np
 
@@ -41,14 +27,13 @@ __all__ = [
     "HOSTS_AXIS",
     "LOCAL_AXIS",
     "TOPO_AXES",
-    "resolve_shard_map",
     "shard_map",
     "psum",
     "ppermute",
     "axis_index",
     "make_miner_mesh",
     "make_topo_mesh",
-    "force_host_device_count",
+    "require_device_count",
     "host_device_count_env",
     "device_count",
     "normalize_cost_analysis",
@@ -63,42 +48,6 @@ HOSTS_AXIS = "hosts"
 LOCAL_AXIS = "local"
 TOPO_AXES = (HOSTS_AXIS, LOCAL_AXIS)
 
-_CHECK_KWARGS = ("check_vma", "check_rep")  # newest first
-
-
-@functools.lru_cache(maxsize=1)
-def resolve_shard_map() -> Callable:
-    """Locate `shard_map` across JAX versions (newest location first)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        fn = getattr(jax.sharding, "shard_map", None)
-    if fn is None:
-        try:
-            from jax.experimental.shard_map import shard_map as fn  # type: ignore
-        except ImportError:  # pragma: no cover - no known jax lacks all three
-            fn = None
-    if fn is None:  # pragma: no cover
-        raise ImportError(
-            "no shard_map found in jax, jax.sharding, or jax.experimental"
-        )
-    return fn
-
-
-@functools.lru_cache(maxsize=1)
-def _check_kwarg_name() -> str | None:
-    """Which replication-check kwarg the resolved shard_map accepts."""
-    fn = resolve_shard_map()
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover - C-implemented fn
-        return _CHECK_KWARGS[0]
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
-        return _CHECK_KWARGS[0]
-    for name in _CHECK_KWARGS:
-        if name in params:
-            return name
-    return None
-
 
 def shard_map(
     f: Callable,
@@ -108,41 +57,18 @@ def shard_map(
     out_specs,
     check_replication: bool = False,
 ) -> Callable:
-    """Version-portable `shard_map(f)` with the check kwarg normalized.
+    """`jax.shard_map(f)` with the replication checker off by default.
 
     `check_replication=False` (the engine default) disables the static
-    replication/VMA checker: the BSP program's out_specs deliberately mix
-    replicated collective results with per-miner outputs, which old checkers
-    reject.
+    VMA checker: the BSP program's out_specs deliberately mix replicated
+    collective results with per-miner outputs.
     """
-    sm = resolve_shard_map()
-    kwargs: dict[str, Any] = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    check_name = _check_kwarg_name()
-    if check_name is not None:
-        kwargs[check_name] = check_replication
-    try:
-        return sm(f, **kwargs)
-    except TypeError:
-        # Signature introspection lied (e.g. a wrapper without the kwarg):
-        # retry with the other spelling, then bare.
-        for name in _CHECK_KWARGS:
-            if name == check_name:
-                continue
-            try:
-                kw = dict(kwargs)
-                kw.pop(check_name, None)
-                kw[name] = check_replication
-                return sm(f, **kw)
-            except TypeError:
-                pass
-        kwargs.pop(check_name, None)
-        return sm(f, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_replication)
 
 
 # ---------------------------------------------------------------- collectives
-# Thin aliases today, but they pin the engine's collective surface to this
-# module: a non-XLA backend (or a tracing/shim layer) only has to replace
-# these two functions and `shard_map` above.
+# Thin aliases that pin the engine's collective surface to this module.
 
 def psum(x, axis_name=MINERS_AXIS):
     """Sum `x` across the mesh axis (every miner gets the total).
@@ -217,63 +143,50 @@ def device_count() -> int:
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
-def host_device_count_env(n: int, env: dict | None = None) -> dict:
-    """Return a copy of `env` (default os.environ) with XLA_FLAGS forcing `n`
-    simulated host devices, replacing any existing device-count flag.
+def _device_count_flags(xla_flags: str, n: int) -> str:
+    """`xla_flags` with the simulated device-count flag set to `n`."""
+    flags = [f for f in xla_flags.split() if not f.startswith(_FORCE_FLAG)]
+    return " ".join([f"{_FORCE_FLAG}={int(n)}", *flags])
 
-    For use when building a *subprocess* environment: the flag must precede
-    the child's first jax init.
+
+def host_device_count_env(n: int, env: dict | None = None) -> dict:
+    """Return a copy of `env` (default os.environ) for a child process that
+    runs on `n` simulated CPU devices.
+
+    XLA_FLAGS forces `n` host devices (replacing any existing device-count
+    flag; it must precede the child's first jax init) and JAX_PLATFORMS is
+    pinned to cpu, so such a child never competes with its parent for an
+    accelerator.
     """
     env = dict(os.environ if env is None else env)
-    flags = [
-        f for f in env.get("XLA_FLAGS", "").split() if not f.startswith(_FORCE_FLAG)
-    ]
-    flags.insert(0, f"{_FORCE_FLAG}={int(n)}")
-    env["XLA_FLAGS"] = " ".join(flags)
+    env["XLA_FLAGS"] = _device_count_flags(env.get("XLA_FLAGS", ""), n)
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
-def force_host_device_count(n: int) -> bool:
-    """Force `n` simulated host devices in *this* process.
+def require_device_count(n: int) -> None:
+    """Make this process run on exactly `n` devices, or raise ValueError.
 
-    Must run before the first jax backend init (jax locks the device count
-    then).  Returns True if the setting can still take effect, False if jax
-    is already initialized with a different count (callers should then fall
-    back to a subprocess with `host_device_count_env`).
+    On the CPU platform `n` simulated host devices are forced, which works
+    only before the first jax backend init (jax locks the device count
+    then).  An accelerator has the devices it has.  Either way a mismatch
+    is an error, never a silent run on a different machine shape.
     """
-    os.environ["XLA_FLAGS"] = host_device_count_env(n)["XLA_FLAGS"]
-    try:
-        already = jax._src.xla_bridge._backends  # type: ignore[attr-defined]
-        initialized = bool(already)
-    except Exception:  # pragma: no cover - private API moved; assume live
-        initialized = True
-    return (not initialized) or jax.device_count() == n
+    os.environ["XLA_FLAGS"] = _device_count_flags(os.environ.get("XLA_FLAGS", ""), n)
+    have = jax.device_count()
+    if have != n:
+        raise ValueError(
+            f"asked for {n} devices but JAX has {have} "
+            f"{jax.default_backend()} device(s) (on CPU, set the device count "
+            "before JAX initializes)"
+        )
 
 
 # ------------------------------------------------------------ cost analysis
 def normalize_cost_analysis(cost) -> dict:
-    """Normalize `Compiled.cost_analysis()` across JAX versions.
-
-    Old JAX returns a dict; newer JAX returns a list with one dict per
-    partition (usually length 1).  Multi-entry lists are merged by summing
-    numeric values (per-partition costs of one SPMD program).  Always returns
-    a plain dict; {} for None/empty.
-    """
+    """`Compiled.cost_analysis()` as a plain dict; {} when there is none."""
     if cost is None:
         return {}
     if isinstance(cost, dict):
         return dict(cost)
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for entry in cost:
-            if not isinstance(entry, dict):
-                continue
-            for k, v in entry.items():
-                if isinstance(v, (int, float)) and isinstance(
-                    merged.get(k, 0.0), (int, float)
-                ):
-                    merged[k] = merged.get(k, 0.0) + v
-                else:
-                    merged.setdefault(k, v)
-        return merged
     raise TypeError(f"unrecognized cost_analysis() return: {type(cost)!r}")

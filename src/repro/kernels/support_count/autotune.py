@@ -12,7 +12,8 @@ Two layers, cheapest first:
      bucket that was measured wins outright;
   2. an *analytic* roofline fallback (the same VPU/HBM model the roofline
      benchmark reports): among power-of-two candidates that divide the
-     bucket-padded dims and fit the VMEM budget, minimize modeled time =
+     bucket-padded dims, fit the VMEM budget and (for the TPU kernel) meet
+     the lowering's (8, 128) block rule, minimize modeled time =
      padded word-ops / VPU throughput + HBM bytes / bandwidth + a per-grid-
      step overhead that penalizes tiny blocks; padding waste is priced in
      because the model runs on padded dims.
@@ -21,7 +22,7 @@ Two layers, cheapest first:
 seed table), so a resolved `RuntimeConfig` — which folds the chosen triple
 into the compiled-program cache key — stays stable across a session's life.
 Point `REPRO_SC_AUTOTUNE` at a seed JSON (the artifact CI uploads) to carry
-measured tunings across processes.
+measured tunings across processes; a file that cannot be read is an error.
 """
 
 from __future__ import annotations
@@ -42,6 +43,7 @@ __all__ = [
     "measure_blocks",
     "modeled_time_us",
     "save_seed_table",
+    "tpu_blocks_ok",
     "vmem_bytes",
 ]
 
@@ -86,12 +88,27 @@ def bucket_dims(b: int, m: int, w: int) -> tuple[int, int, int]:
     return _pow2ceil(b, 8), _pow2ceil(m, 128), _pow2ceil(w, 8)
 
 
+def tpu_blocks_ok(blocks: tuple[int, int, int], wp: int) -> bool:
+    """Whether the TPU lowering accepts these blocks for a `wp`-word sweep.
+
+    Every block's last dim must be a multiple of 128 lanes or the whole
+    axis, and its second-to-last a multiple of 8 sublanes: `bm` lies on
+    lanes (db and out blocks), `bb` on sublanes (occ and out), and `bw` is
+    the lane dim of the occ block `(bb, bw)`, so a `bw` below 128 must span
+    all `wp` words.
+    """
+    bb, bm, bw = blocks
+    return bb % 8 == 0 and bm % 128 == 0 and (bw == wp or bw % 128 == 0)
+
+
 def candidate_blocks(b: int, m: int, w: int, impl: str = "pallas"):
     """Power-of-two (bb, bm, bw) triples that divide the bucketed dims and
-    fit the VMEM budget."""
+    fit the VMEM budget; for the TPU kernel ("pallas" and its interpreter)
+    only triples `tpu_blocks_ok` accepts."""
     bp, mp, wp = bucket_dims(b, m, w)
     cand_m = _CAND_M_GPU if impl == "pallas_gpu" else _CAND_M
     cand_w = _CAND_W_GPU if impl == "pallas_gpu" else _CAND_W
+    tpu = impl in ("pallas", "pallas_interpret")
     out = []
     for bb in _CAND_B:
         if bb > bp:
@@ -100,12 +117,12 @@ def candidate_blocks(b: int, m: int, w: int, impl: str = "pallas"):
             if bm > mp:
                 continue
             for bw in cand_w:
-                if bw > wp:
+                if bw > wp or (tpu and not tpu_blocks_ok((bb, bm, bw), wp)):
                     continue
                 if vmem_bytes(bb, bm, bw) <= VMEM_BUDGET:
                     out.append((bb, bm, bw))
-    # tiny shapes can undercut every candidate floor
-    return out or [(min(8, bp), min(128, mp), min(8, wp))]
+    # never empty: bucket_dims floors every dim at a candidate that fits
+    return out
 
 
 def modeled_time_us(b: int, m: int, w: int, blocks: tuple[int, int, int]) -> float:
@@ -174,7 +191,15 @@ def load_seed_table(path: str) -> int:
     global _seed_gen
     with open(path) as f:
         rows = json.load(f)
-    _seed_rows.extend(rows["rows"] if isinstance(rows, dict) else rows)
+    rows = rows["rows"] if isinstance(rows, dict) else rows
+    for row in rows:  # a malformed row fails here, not at dispatch
+        if len(row["bucket"]) != 3 or len(row["blocks"]) != 3:
+            raise ValueError(f"seed row needs 3-dim bucket and blocks: {row}")
+        if row.get("impl", "pallas") == "pallas" and not tpu_blocks_ok(
+                tuple(row["blocks"]), row["bucket"][2]):
+            raise ValueError(f"seed row has blocks the TPU refuses: {row}")
+        float(row["time_us"])
+    _seed_rows.extend(rows)
     _seed_gen += 1
     _choose.cache_clear()
     return len(_seed_rows)
@@ -196,11 +221,15 @@ def save_seed_table(path: str, rows: list[dict]) -> str:
 
 def _maybe_load_env() -> None:
     path = os.environ.get(_ENV_SEED)
-    if path and os.path.exists(path):
-        try:
-            load_seed_table(path)
-        except (OSError, ValueError, KeyError):
-            pass  # a bad seed file must never break kernel dispatch
+    if not path:
+        return
+    try:
+        load_seed_table(path)
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        raise RuntimeError(
+            f"{_ENV_SEED}={path!r}: cannot load the support-count seed table "
+            f"({type(e).__name__}: {e}); fix the file or unset the variable"
+        ) from e
 
 
 _maybe_load_env()

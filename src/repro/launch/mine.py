@@ -15,11 +15,12 @@ and so is the test statistic (--stat fisher|chi2) for the testing
 objectives.  For sustained query traffic against a warm session use
 `repro.launch.mine_serve`.
 
-Set --devices N to fork with XLA_FLAGS=--xla_force_host_platform_device_count=N
-(one miner per device, as on a real pod slice); with --devices 0 the current
-jax device set is used.  --no-steal reproduces the paper's naive baseline.
---top-k prints the most significant mined itemsets (the run's actual
-deliverable) and --patterns-out exports the full ResultSet as TSV/JSON.
+--devices N runs one miner on each of exactly N devices (on CPU, N
+simulated host devices are forced); the run fails when JAX has a different
+number.  With --devices 0 the current jax device set is used.  --no-steal
+reproduces the paper's naive baseline.  --top-k prints the most significant
+mined itemsets (the run's actual deliverable) and --patterns-out exports the
+full ResultSet as TSV/JSON.
 Per-miner stacks are auto-sized by `RuntimeConfig.resolve` (items per miner,
 clamped by word-width-aware stack memory); --stack-cap overrides.
 
@@ -131,11 +132,16 @@ def main(argv=None):
         args.devices = topology.n_proc
 
     if args.devices:
-        from repro.core.collectives import force_host_device_count
+        from repro.core.collectives import require_device_count
 
-        if not force_host_device_count(args.devices):
-            print(f"[warn] jax already initialized; --devices {args.devices} "
-                  "ignored (set XLA_FLAGS before launch)", file=sys.stderr)
+        try:
+            require_device_count(args.devices)
+        except ValueError as e:
+            ap.error(f"--devices {args.devices}: {e}")
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.api import (
         PIPELINES,

@@ -46,8 +46,10 @@ def main(argv=None):
                     help="test statistic served by the sessions")
     ap.add_argument("--devices", type=int, default=0)
     ap.add_argument("--expand-batch", type=int, default=16)
-    ap.add_argument("--kernel", default="ref",
-                    choices=["ref", "pallas", "pallas_interpret"])
+    ap.add_argument("--kernel", default="auto",
+                    choices=["auto", "ref", "pallas", "pallas_interpret"],
+                    help="support-count kernel (auto: pallas on TPU, ref "
+                         "on CPU)")
     ap.add_argument("--top-k", type=int, default=3,
                     help="patterns shown per query (0 = summary line only)")
     ap.add_argument("--concurrency", type=int, default=1,
@@ -74,11 +76,16 @@ def main(argv=None):
         args.queries = min(args.queries, 4)
 
     if args.devices:
-        from repro.core.collectives import force_host_device_count
+        from repro.core.collectives import require_device_count
 
-        if not force_host_device_count(args.devices):
-            print(f"[warn] jax already initialized; --devices {args.devices} "
-                  "ignored (set XLA_FLAGS before launch)", file=sys.stderr)
+        try:
+            require_device_count(args.devices)
+        except ValueError as e:
+            ap.error(f"--devices {args.devices}: {e}")
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.api import (
         PIPELINES, AlgorithmConfig, Dataset, RuntimeConfig,

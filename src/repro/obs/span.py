@@ -21,8 +21,6 @@ queryable host timeline for free.
 `jax_profiler=True` additionally enters a ``jax.profiler.TraceAnnotation``
 per span, so when a device profile is being captured (``jax.profiler.trace``)
 the host spans line up with the XLA device timeline in the same viewer.
-The bridge is best-effort: absent/old jax profiler APIs degrade to plain
-span recording.
 """
 
 from __future__ import annotations
@@ -46,12 +44,9 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._annotation = None
         if jax_profiler:
-            try:
-                from jax.profiler import TraceAnnotation
+            from jax.profiler import TraceAnnotation
 
-                self._annotation = TraceAnnotation
-            except Exception:  # profiler API moved/absent: spans still record
-                self._annotation = None
+            self._annotation = TraceAnnotation
 
     def _now_us(self) -> float:
         return (time.perf_counter_ns() - self._epoch_ns) / 1e3

@@ -1,6 +1,6 @@
 """Multi-process mesh bring-up + argument/result marshalling (DESIGN.md §12).
 
-Three concerns, all version-portable behind this module:
+Three concerns, all behind this module:
 
 1. **Bring-up** — `init_distributed()` wraps `jax.distributed.initialize`
    with the CPU-collectives (gloo) configuration a simulated multi-host run
@@ -58,12 +58,7 @@ def init_distributed(
     """
     import jax
 
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        # flag absent on this jax version: TPU/GPU backends bring their own
-        # transport; CPU multi-process will fail loudly at initialize()
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -151,9 +146,11 @@ def launch_local_cluster(
 
     Each child gets `spec` plus the cluster coordinates
     (coordinator/num_processes/process_id) as its argv[1] JSON, and an
-    environment forcing `devices_per_process` simulated host devices
-    (replacing any inherited device-count flag — the harness itself must
-    not touch jax before calling `init_distributed`).  Returns the last
+    environment pinned to the CPU platform with `devices_per_process`
+    simulated host devices (replacing any inherited device-count flag — the
+    harness itself must not touch jax before calling `init_distributed`).
+    The cluster is a CPU gloo rehearsal by design: its children never
+    compete with the parent for an accelerator.  Returns the last
     stdout line of process 0 parsed as JSON; raises with the children's
     stderr on any nonzero exit.
     """

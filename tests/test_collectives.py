@@ -1,9 +1,8 @@
-"""Version-portability layer (core/collectives.py) unit tests.
+"""Distributed-runtime layer (core/collectives.py) unit tests.
 
-Covers the three shims the engine depends on: shard_map resolution across
-JAX versions (incl. the check_vma/check_rep kwarg rename), cost_analysis()
-normalization (dict vs list-of-dict returns), and simulated multi-device
-mesh setup on CPU (subprocess: the flag must precede first jax init).
+Covers the shard_map wrapper and its collectives, cost_analysis()
+normalization, the device-count helpers, and simulated multi-device mesh
+setup on CPU (subprocess: the flag must precede first jax init).
 """
 
 import json
@@ -24,27 +23,13 @@ from repro.core.collectives import (
     host_device_count_env,
     make_miner_mesh,
     normalize_cost_analysis,
-    resolve_shard_map,
+    require_device_count,
 )
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 # ----------------------------------------------------------------- shard_map
-def test_resolve_shard_map_finds_a_callable():
-    fn = resolve_shard_map()
-    assert callable(fn)
-    # resolution must agree with whatever this jax actually exposes
-    candidates = [getattr(jax, "shard_map", None),
-                  getattr(jax.sharding, "shard_map", None)]
-    try:
-        from jax.experimental.shard_map import shard_map as exp_sm
-        candidates.append(exp_sm)
-    except ImportError:
-        pass
-    assert any(fn is c for c in candidates if c is not None)
-
-
 def test_shard_map_wrapper_runs_collectives():
     """The wrapped shard_map compiles a psum+ppermute program (any P>=1)."""
     mesh = make_miner_mesh()
@@ -86,14 +71,9 @@ def test_shard_map_wrapper_mixed_replication_specs():
 # ---------------------------------------------------------- cost_analysis()
 def test_normalize_cost_analysis_shapes():
     assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis([]) == {}
-    assert normalize_cost_analysis({"flops": 2.0}) == {"flops": 2.0}
-    assert normalize_cost_analysis([{"flops": 2.0}]) == {"flops": 2.0}
-    # multi-partition lists merge by summing numerics
-    got = normalize_cost_analysis(
-        [{"flops": 2.0, "name": "a"}, {"flops": 3.0, "bytes": 1.0}]
-    )
-    assert got["flops"] == 5.0 and got["bytes"] == 1.0 and got["name"] == "a"
+    cost = {"flops": 2.0}
+    got = normalize_cost_analysis(cost)
+    assert got == {"flops": 2.0} and got is not cost  # a copy
     with pytest.raises(TypeError):
         normalize_cost_analysis(42)
 
@@ -116,6 +96,20 @@ def test_host_device_count_env_replaces_stale_flag():
     assert "--foo=bar" in flags
     assert sum(f.startswith("--xla_force_host_platform_device_count") for f in flags) == 1
     assert env["XLA_FLAGS"].endswith("--foo=bar")  # input not mutated
+
+
+def test_host_device_count_env_pins_cpu():
+    """Children on simulated host devices never reach for an accelerator."""
+    out = host_device_count_env(4, {"JAX_PLATFORMS": "tpu"})
+    assert out["JAX_PLATFORMS"] == "cpu"
+
+
+def test_require_device_count(monkeypatch):
+    monkeypatch.setenv("XLA_FLAGS", "--foo=bar")  # restored after the test
+    have = jax.device_count()
+    require_device_count(have)  # the devices JAX has: fine
+    with pytest.raises(ValueError, match=f"asked for {have + 1} devices"):
+        require_device_count(have + 1)
 
 
 def test_miner_mesh_1d_axis():

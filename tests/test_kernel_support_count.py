@@ -248,6 +248,50 @@ def test_seed_table_wins(tmp_path):
     assert (bb, bm, bw) != (0, 0, 0)
 
 
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
+@pytest.mark.parametrize("w", [1, 8, 12, 22, 100, 129, 400, 1000])
+def test_candidate_blocks_meet_tpu_lowering_rule(impl, w):
+    """Every offered triple is one the TPU lowering accepts: bb a multiple
+    of 8, bm of 128, and bw the whole padded word axis or a multiple of 128
+    (the occ block (bb, bw) puts bw on lanes)."""
+    for b in (1, 16, 100, 512):
+        for m in (5, 512, 4096, 16384, 262144):
+            wp = autotune.bucket_dims(b, m, w)[2]
+            cands = autotune.candidate_blocks(b, m, w, impl)
+            assert cands
+            for bb, bm, bw in cands:
+                assert bb % 8 == 0 and bm % 128 == 0, (b, m, w, (bb, bm, bw))
+                assert bw == wp or bw % 128 == 0, (b, m, w, (bb, bm, bw))
+                assert autotune.tpu_blocks_ok((bb, bm, bw), wp)
+            assert autotune.choose_blocks(b, m, w, impl) in cands
+
+
+def test_tpu_blocks_ok_refuses_narrow_partial_word_blocks():
+    assert autotune.tpu_blocks_ok((16, 2048, 32), 32)
+    assert autotune.tpu_blocks_ok((16, 512, 128), 512)
+    assert not autotune.tpu_blocks_ok((16, 2048, 16), 32)   # bw < 128, not whole
+    assert not autotune.tpu_blocks_ok((16, 64, 32), 32)     # bm below a lane row
+    assert not autotune.tpu_blocks_ok((4, 2048, 32), 32)    # bb below a sublane tile
+
+
+@pytest.mark.parametrize("content", [
+    None, "not json", '{"rows": [{"impl": 1}]}',
+    # a TPU-kernel row whose occ block (16, 16) the lowering refuses at W=32
+    '[{"impl": "pallas", "bucket": [16, 4096, 32], "blocks": [16, 2048, 16],'
+    ' "time_us": 1.0}]',
+])
+def test_unreadable_seed_file_is_an_error(tmp_path, monkeypatch, content):
+    path = tmp_path / "seed.json"
+    if content is not None:
+        path.write_text(content)
+    monkeypatch.setenv("REPRO_SC_AUTOTUNE", str(path))
+    try:
+        with pytest.raises(RuntimeError, match="REPRO_SC_AUTOTUNE"):
+            autotune._maybe_load_env()
+    finally:
+        autotune.clear_seed_table()
+
+
 def test_stable_jit_across_ragged_shapes():
     """The eager wrapper pads to pow2 buckets before its inner jit: every
     shape in one bucket reuses one traced program (the old wrapper re-jitted
