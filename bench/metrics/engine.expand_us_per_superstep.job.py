@@ -1,0 +1,9 @@
+"""Device time of the engine's ops under its `expand` scope (the support-count
+kernel, candidate and closure bookkeeping), mean over chips, per superstep
+of the traced window, in us (see parts.py)."""
+
+from bench.metrics.parts import scope_us_per_superstep
+
+
+def read(r):
+    return scope_us_per_superstep(r, "expand")

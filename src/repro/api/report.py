@@ -57,6 +57,11 @@ class PhaseReport:
     ckpt_writes: int = 0       # frontier checkpoints written this phase
     ckpt_bytes: int = 0        # total frontier payload bytes written
     ckpt_path: str | None = None  # newest published step dir (None = none)
+    # the compiled program that ran this pass (a `jax.stages.Compiled`, the
+    # session's cached executable): its optimized HLO (`as_text()`) names
+    # each op's superstep part — expand, steal, sync, trace — in its
+    # metadata op_name, which attributes a device profile's ops to them
+    compiled: object = field(default=None, repr=False, compare=False)
 
     @property
     def stats(self):

@@ -484,6 +484,10 @@ class MinerSession:
                         raw, phase_out_specs(cfg, mesh_axis(self.mesh))
                     )
             with self.tracer.span("postprocess"):
+                # the device-to-host copy of the phase outputs: where the
+                # host waits for the program, so the rest is host work
+                with self.tracer.span("readback"):
+                    raw = jax.device_get(raw)
                 out = postprocess_phase(
                     raw, packed=dataset.packed, n_proc=self.n_devices, cfg=cfg,
                     mode=mode, thr=ctx["thr"], start_sup=ctx["start_sup"],
@@ -526,6 +530,7 @@ class MinerSession:
             ckpt_writes=ckpt["writes"],
             ckpt_bytes=ckpt["bytes"],
             ckpt_path=ckpt["path"],
+            compiled=entry.compiled,
         )
 
     def _run_segmented(self, entry, dataset, cfg, *, mode, alpha, delta,
@@ -620,8 +625,8 @@ class MinerSession:
         self._should_stop = should_stop if self.runtime.ckpt_period else None
         self._phase_seq = 0
         try:
-            with self.tracer.span(f"query:{type(query).__name__}",
-                                  dataset=dataset.name):
+            with self.tracer.request(), self.tracer.span(
+                    f"query:{type(query).__name__}", dataset=dataset.name):
                 report = query.run(self, dataset)
         finally:
             self._stream = None
@@ -691,7 +696,7 @@ class MinerSession:
                 min_sup=min_sup, correction_factor=k, delta=delta,
                 filter_host=filter_host, dropped=phase_out.emit_dropped,
                 item_names=dataset.item_names, statistic=statistic,
-                stream=stream,
+                stream=stream, tracer=self.tracer,
             )
 
     def _root_record(self, dataset: Dataset, phase_out: MineOutput,
@@ -801,8 +806,9 @@ def _pipeline_three_phase(session: MinerSession, dataset: Dataset,
             query_tag="significant", alpha=alpha, statistic=statistic, t0=t0,
             min_sup=min_sup, lam=ph1.lam_final,
         )
-    k = int(ph2.output.hist[min_sup:].sum())
-    delta = alpha / max(k, 1)
+    with session.tracer.span("correction"):
+        k = int(ph2.output.hist[min_sup:].sum())
+        delta = alpha / max(k, 1)
     # phase 3: significance testing at delta
     ph3 = session.run_phase(dataset, "test", min_sup=min_sup, delta=delta,
                             alpha=alpha, statistic=statistic)
@@ -815,11 +821,12 @@ def _pipeline_three_phase(session: MinerSession, dataset: Dataset,
     # the device already filtered at delta; reconstruct + exact stats only
     # (the root closed set is appended iff the statistic counts it — it is
     # in ph3's n_sig exactly when significant, so list and count agree)
+    with session.tracer.span("correction"):
+        records = session._root_record(dataset, ph3.output, statistic, delta,
+                                       min_sup)
     results = session._build_results(
         dataset, ph3.output, alpha=alpha, min_sup=min_sup, k=k, delta=delta,
-        filter_host=False, statistic=statistic,
-        records=session._root_record(dataset, ph3.output, statistic, delta,
-                                     min_sup),
+        filter_host=False, statistic=statistic, records=records,
     )
     return MineReport(
         dataset=dataset.name,
@@ -871,22 +878,23 @@ def _pipeline_fused23(session: MinerSession, dataset: Dataset,
             alpha=alpha, statistic=statistic, t0=t0, min_sup=min_sup,
             lam=ph1.lam_final, delta=alpha, filter_host=True,
         )
-    h2 = ph2.output.hist2d
-    sups_grid = np.arange(n + 1)
-    mask = (h2 > 0) & (sups_grid[:, None] >= min_sup)
-    k = int(h2[mask].sum())
-    delta = alpha / max(k, 1)
-    xs, ns = np.nonzero(mask)
-    pv = stat.pvalue(xs, ns, n, n_pos) if len(xs) else np.zeros(0)
-    sig_mask = pv <= delta
-    n_sig = int(h2[xs[sig_mask], ns[sig_mask]].sum()) if len(xs) else 0
+    with session.tracer.span("correction"):
+        h2 = ph2.output.hist2d
+        sups_grid = np.arange(n + 1)
+        mask = (h2 > 0) & (sups_grid[:, None] >= min_sup)
+        k = int(h2[mask].sum())
+        delta = alpha / max(k, 1)
+        xs, ns = np.nonzero(mask)
+        pv = stat.pvalue(xs, ns, n, n_pos) if len(xs) else np.zeros(0)
+        sig_mask = pv <= delta
+        n_sig = int(h2[xs[sig_mask], ns[sig_mask]].sum()) if len(xs) else 0
+        # root appended iff significant — the 2-D histogram counted it then
+        records = session._root_record(dataset, ph2.output, statistic, delta,
+                                       min_sup)
     # records were emitted at the alpha superset level; exact-filter at delta
-    # (root appended iff significant — the 2-D histogram counted it then)
     results = session._build_results(
         dataset, ph2.output, alpha=alpha, min_sup=min_sup, k=k, delta=delta,
-        filter_host=True, statistic=statistic,
-        records=session._root_record(dataset, ph2.output, statistic, delta,
-                                     min_sup),
+        filter_host=True, statistic=statistic, records=records,
     )
     return MineReport(
         dataset=dataset.name,

@@ -20,6 +20,14 @@ One logical miner per device.  The whole search runs as a single compiled
               §4.4's piggyback; staleness only costs work, never
               correctness).
 
+Each phase runs under a `jax.named_scope` — `expand`, `steal` (the
+hunger census and the exchange), `sync` (the lambda sync, the
+superstep's counters and termination, the loop condition and the
+terminal psums), plus `trace` for the ring write when `trace_period >
+0` — so the compiled program's op metadata, and with it a device
+profile, names the part of the superstep each op belongs to (DESIGN.md
+§9).  Scopes change metadata only, never the instructions.
+
 Each per-miner stack is a circular deque over fixed [stack_cap, W] storage
 (core/deque.py): EXPAND pops/pushes at the logical top by pointer
 arithmetic, a steal donates the logical bottom-k with O(steal_max) gathers
@@ -417,67 +425,78 @@ def build_mine_step(
         (occ_stack, meta, sp, head, hist, hist_snap, g_hist_acc, hist2d, lam,
          t, stats, out_occ, out_meta, out_ptr, n_sig, trace, _work) = carry
         stats_before = stats
-        (occ_stack, meta, sp, hist, hist2d, stats, out_occ, out_meta, out_ptr,
-         sig_cnt) = expand(
-            occ_stack, meta, sp, head, hist, hist2d, lam, stats, db_tiles,
-            pos_mask, out_occ, out_meta, out_ptr, delta, n_act, npos_act,
-        )
-        n_sig = n_sig + sig_cnt
-        # the [P]-int hunger census: REQUEST side of the steal exchange,
-        # gate for its payload ppermute, and the exact termination test
-        # (steals only redistribute; they cannot turn an all-empty
-        # superstep into work)
-        hungry_vec = hunger_census(sp, n_proc, axis)
-        n_hungry = jnp.sum(hungry_vec)
-        if cfg.steal_enabled:
-            occ_stack, meta, sp, head, got, gave, k_given, k_recv = steal_round(
-                t, hungry_vec, n_hungry, occ_stack, meta, sp, head
+        # each part of the superstep runs under a named scope (expand,
+        # steal, trace, sync), so every op of the compiled program names its
+        # part in its metadata op_name and a device profile's time can be
+        # attributed to the parts
+        with jax.named_scope("expand"):
+            (occ_stack, meta, sp, hist, hist2d, stats, out_occ, out_meta,
+             out_ptr, sig_cnt) = expand(
+                occ_stack, meta, sp, head, hist, hist2d, lam, stats, db_tiles,
+                pos_mask, out_occ, out_meta, out_ptr, delta, n_act, npos_act,
             )
-            stats = stats.at[Stat.STEALS_GOT].add(got)
-            stats = stats.at[Stat.GIVES].add(gave)
-            stats = stats.at[Stat.STOLEN_NODES].add(k_given)
-            stats = stats.at[Stat.STEAL_ROUNDS].add(
-                (n_hungry > 0).astype(jnp.int32)
-            )
-        else:
-            k_given = k_recv = jnp.int32(0)
-        stats = stats.at[Stat.IDLE_STEPS].add((sp == 0).astype(jnp.int32))
-        stats = stats.at[Stat.SUPERSTEPS].add(1)
+            n_sig = n_sig + sig_cnt
+        with jax.named_scope("steal"):
+            # the [P]-int hunger census: REQUEST side of the steal exchange,
+            # gate for its payload ppermute, and the exact termination test
+            # (steals only redistribute; they cannot turn an all-empty
+            # superstep into work)
+            hungry_vec = hunger_census(sp, n_proc, axis)
+            n_hungry = jnp.sum(hungry_vec)
+            if cfg.steal_enabled:
+                occ_stack, meta, sp, head, got, gave, k_given, k_recv = (
+                    steal_round(t, hungry_vec, n_hungry, occ_stack, meta, sp,
+                                head))
+                stats = stats.at[Stat.STEALS_GOT].add(got)
+                stats = stats.at[Stat.GIVES].add(gave)
+                stats = stats.at[Stat.STOLEN_NODES].add(k_given)
+                stats = stats.at[Stat.STEAL_ROUNDS].add(
+                    (n_hungry > 0).astype(jnp.int32)
+                )
+            else:
+                k_given = k_recv = jnp.int32(0)
+        with jax.named_scope("sync"):  # the superstep's own counters
+            stats = stats.at[Stat.IDLE_STEPS].add((sp == 0).astype(jnp.int32))
+            stats = stats.at[Stat.SUPERSTEPS].add(1)
 
         if cfg.trace_period:
-            # record *before* global_sync so LAMBDA is the value in force
-            # during this superstep's expand; volumes are this-step stat
-            # deltas.  Unsampled steps write to slot == trace_cap, which
-            # mode="drop" discards — no branch, no psum, one 11-int store.
-            deltas = stats - stats_before
-            fired = (n_hungry > 0) & bool(cfg.steal_enabled)
-            rec = jnp.stack([
-                t,                           # TraceField.STEP
-                lam,                         # TraceField.LAMBDA
-                sp,                          # TraceField.DEPTH
-                n_hungry,                    # TraceField.HUNGRY
-                fired.astype(jnp.int32),     # TraceField.FIRED
-                deltas[Stat.POPPED],         # TraceField.POPPED
-                deltas[Stat.PUSHED],         # TraceField.PUSHED
-                deltas[Stat.CLOSED],         # TraceField.CLOSED
-                sig_cnt,                     # TraceField.EMITTED
-                k_given,                     # TraceField.DONATED
-                k_recv,                      # TraceField.RECEIVED
-            ]).astype(jnp.int32)
-            sampled = (t % cfg.trace_period) == 0
-            idx = t // cfg.trace_period
-            slot = jnp.where(sampled, idx % cfg.trace_cap, cfg.trace_cap)
-            trace = trace.at[slot].set(rec, mode="drop")
-            stats = stats.at[Stat.TRACE_DROPPED].add(
-                (sampled & (idx >= cfg.trace_cap)).astype(jnp.int32)
-            )
+            with jax.named_scope("trace"):
+                # record *before* global_sync so LAMBDA is the value in
+                # force during this superstep's expand; volumes are this-
+                # step stat deltas.  Unsampled steps write to slot ==
+                # trace_cap, which mode="drop" discards — no branch, no
+                # psum, one 11-int store.
+                deltas = stats - stats_before
+                fired = (n_hungry > 0) & bool(cfg.steal_enabled)
+                rec = jnp.stack([
+                    t,                           # TraceField.STEP
+                    lam,                         # TraceField.LAMBDA
+                    sp,                          # TraceField.DEPTH
+                    n_hungry,                    # TraceField.HUNGRY
+                    fired.astype(jnp.int32),     # TraceField.FIRED
+                    deltas[Stat.POPPED],         # TraceField.POPPED
+                    deltas[Stat.PUSHED],         # TraceField.PUSHED
+                    deltas[Stat.CLOSED],         # TraceField.CLOSED
+                    sig_cnt,                     # TraceField.EMITTED
+                    k_given,                     # TraceField.DONATED
+                    k_recv,                      # TraceField.RECEIVED
+                ]).astype(jnp.int32)
+                sampled = (t % cfg.trace_period) == 0
+                idx = t // cfg.trace_period
+                slot = jnp.where(sampled, idx % cfg.trace_cap, cfg.trace_cap)
+                trace = trace.at[slot].set(rec, mode="drop")
+                stats = stats.at[Stat.TRACE_DROPPED].add(
+                    (sampled & (idx >= cfg.trace_cap)).astype(jnp.int32)
+                )
 
-        lam, g_hist_acc, hist_snap = global_sync(
-            t, hist, hist_snap, g_hist_acc, lam, thr
-        )
-        work = jnp.int32(n_proc) - n_hungry
+        with jax.named_scope("sync"):  # lambda sync, termination count
+            lam, g_hist_acc, hist_snap = global_sync(
+                t, hist, hist_snap, g_hist_acc, lam, thr
+            )
+            work = jnp.int32(n_proc) - n_hungry
+            t = t + 1
         return (occ_stack, meta, sp, head, hist, hist_snap, g_hist_acc,
-                hist2d, lam, t + 1, stats, out_occ, out_meta, out_ptr, n_sig,
+                hist2d, lam, t, stats, out_occ, out_meta, out_ptr, n_sig,
                 trace, work)
 
     def program(init_occ, init_meta, init_sp, db_tiles, pos_mask, thr,
@@ -508,9 +527,11 @@ def build_mine_step(
              work) = carry
             # work (miners with non-empty stacks) was psum'd at the previous
             # superstep boundary:
-            return (work > 0) & (t < cfg.max_steps)  # exact BSP termination
+            with jax.named_scope("sync"):
+                return (work > 0) & (t < cfg.max_steps)  # exact BSP termination
 
-        work0 = jnp.int32(n_proc) - jnp.sum(hunger_census(sp, n_proc, axis))
+        with jax.named_scope("steal"):
+            work0 = jnp.int32(n_proc) - jnp.sum(hunger_census(sp, n_proc, axis))
         carry = (occ_stack, meta, sp, head, hist, hist_snap, g_hist_acc,
                  hist2d, lam0, t, stats, out_occ, out_meta, out_ptr, n_sig,
                  trace, work0)
@@ -524,9 +545,10 @@ def build_mine_step(
         # one exact full-histogram psum at termination (the in-loop lambda
         # only ever saw sync_period-stale deltas; postprocess replays the
         # recursion from this exact histogram)
-        g_hist = collectives.psum(hist, axis)
-        g_hist2d = collectives.psum(hist2d, axis)  # once, at termination — not per step
-        g_sig = collectives.psum(n_sig, axis)
+        with jax.named_scope("sync"):
+            g_hist = collectives.psum(hist, axis)
+            g_hist2d = collectives.psum(hist2d, axis)  # once, at termination — not per step
+            g_sig = collectives.psum(n_sig, axis)
         return (
             g_hist, lam, t, stats[None], out_occ[None], out_meta[None],
             out_ptr[None], g_sig, trace[None], g_hist2d,
@@ -552,7 +574,8 @@ def build_mine_step(
             # work was psum'd at the previous boundary — uniform across
             # miners, so the loop exits in lockstep; t_stop is runtime data
             # (no recompile per segment)
-            return (work > 0) & (t < t_stop)
+            with jax.named_scope("sync"):
+                return (work > 0) & (t < t_stop)
 
         carry = lax.while_loop(
             cond_fn,

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import json
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -245,6 +246,7 @@ def build_result_set(
     item_names: tuple[str, ...] | None = None,
     statistic: str | None = "fisher",
     stream: ResultStream | None = None,
+    tracer=None,
 ) -> ResultSet:
     """Emitted records -> deduped, exactly-(re)tested, sorted ResultSet.
 
@@ -253,8 +255,13 @@ def build_result_set(
     testing entirely — patterns carry NaN P/q and sort by support (the
     closed-frequent objective).  `stream` delivers the top-`head_k` head to
     a callback mid-build (see `ResultStream`); the returned ResultSet is
-    identical either way.
+    identical either way.  `tracer` (a `repro.obs.SpanTracer`) times the
+    build's three steps as spans `closure` (reconstruction and dedup),
+    `pvalues` (the host test and the delta filter) and `patterns` (building
+    and sorting the `Pattern`s); streaming interleaves closures with
+    pattern building, so there `closure` holds both.
     """
+    span = tracer.span if tracer is not None else _no_span
     occ = np.asarray(occ, dtype=np.uint32).reshape(-1, db_bits.shape[1])
     sup = np.asarray(sup, dtype=np.int64).reshape(-1)
     pos_sup = np.asarray(pos_sup, dtype=np.int64).reshape(-1)
@@ -264,6 +271,7 @@ def build_result_set(
         patterns = _build_patterns_streaming(
             occ, sup, pos_sup, db_bits, n=n, n_pos=n_pos, k=k, delta=delta,
             filter_host=filter_host, statistic=statistic, stream=stream,
+            span=span,
         )
         return ResultSet(
             patterns=patterns,
@@ -278,31 +286,15 @@ def build_result_set(
             statistic=statistic,
         )
 
-    closures = reconstruct_closures(occ, sup, db_bits)
-    closures, sup, pos_sup = dedup_by_closure(closures, sup, pos_sup)
+    with span("closure"):
+        closures = reconstruct_closures(occ, sup, db_bits)
+        closures, sup, pos_sup = dedup_by_closure(closures, sup, pos_sup)
 
-    patterns = []
-    if len(closures) and statistic is None:
-        for i in range(len(closures)):
-            patterns.append(Pattern(
-                items=closures[i],
-                support=int(sup[i]),
-                pos_support=int(pos_sup[i]),
-                pvalue=float("nan"),
-                qvalue=float("nan"),
-            ))
-    elif len(closures):
-        pvals = get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
-        keep = pvals <= delta if filter_host else np.ones(len(closures), bool)
-        for i in np.flatnonzero(keep):
-            p = float(pvals[i])
-            patterns.append(Pattern(
-                items=closures[i],
-                support=int(sup[i]),
-                pos_support=int(pos_sup[i]),
-                pvalue=p,
-                qvalue=min(1.0, p * k),
-            ))
+    if len(closures) and statistic is not None:
+        with span("pvalues"):
+            pvals = get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
+            keep = np.flatnonzero(pvals <= delta if filter_host
+                                  else np.ones(len(closures), bool))
 
     # The root closed set (closure of the empty itemset) never rides the
     # device buffers, so it only appears here if the caller appended its
@@ -312,8 +304,28 @@ def build_result_set(
     # it significant (chi2's root P is 0.5), and the session pipelines /
     # ClosedFrequentQuery append it exactly when their host-side root count
     # does, keeping the pattern list consistent with n_significant.
-
-    patterns.sort(key=_sort_key(statistic))
+    with span("patterns"):
+        patterns = []
+        if len(closures) and statistic is None:
+            for i in range(len(closures)):
+                patterns.append(Pattern(
+                    items=closures[i],
+                    support=int(sup[i]),
+                    pos_support=int(pos_sup[i]),
+                    pvalue=float("nan"),
+                    qvalue=float("nan"),
+                ))
+        elif len(closures):
+            for i in keep:
+                p = float(pvals[i])
+                patterns.append(Pattern(
+                    items=closures[i],
+                    support=int(sup[i]),
+                    pos_support=int(pos_sup[i]),
+                    pvalue=p,
+                    qvalue=min(1.0, p * k),
+                ))
+        patterns.sort(key=_sort_key(statistic))
     return ResultSet(
         patterns=patterns,
         n_transactions=n,
@@ -328,6 +340,10 @@ def build_result_set(
     )
 
 
+def _no_span(name: str):
+    return nullcontext()
+
+
 def _sort_key(statistic: str | None):
     """The one canonical pattern ordering (streaming finality depends on it:
     the partial key (pvalue, -support) must be a prefix of this full key)."""
@@ -338,7 +354,7 @@ def _sort_key(statistic: str | None):
 
 def _build_patterns_streaming(
     occ, sup, pos_sup, db_bits, *, n, n_pos, k, delta, filter_host,
-    statistic, stream: ResultStream,
+    statistic, stream: ResultStream, span=_no_span,
 ) -> list[Pattern]:
     """Reconstruct records in significance order, stream the head early.
 
@@ -361,43 +377,47 @@ def _build_patterns_streaming(
         partial = lambda j: (-int(sup[j]),)                    # noqa: E731
         partial_p = lambda p: (-p.support,)                    # noqa: E731
     else:
-        pvals = (get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
-                 if n_rec else np.zeros(0))
-        idx = np.flatnonzero(pvals <= delta) if filter_host else np.arange(n_rec)
-        order = (idx[np.lexsort((idx, -sup[idx], pvals[idx]))]
-                 if len(idx) else idx)
+        with span("pvalues"):
+            pvals = (get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
+                     if n_rec else np.zeros(0))
+            idx = (np.flatnonzero(pvals <= delta) if filter_host
+                   else np.arange(n_rec))
+            order = (idx[np.lexsort((idx, -sup[idx], pvals[idx]))]
+                     if len(idx) else idx)
         partial = lambda j: (float(pvals[j]), -int(sup[j]))    # noqa: E731
         partial_p = lambda p: (p.pvalue, -p.support)           # noqa: E731
 
-    seen: set[tuple[int, ...]] = set()
-    patterns: list[Pattern] = []
-    head_sent = False
-    for lo in range(0, max(len(order), 1), stream.chunk):
-        sel = order[lo:lo + stream.chunk]
-        closures = reconstruct_closures(occ[sel], sup[sel], db_bits)
-        for j, c in zip(sel, closures):
-            if c in seen:
+    with span("closure"):  # interleaved with building the patterns
+        seen: set[tuple[int, ...]] = set()
+        patterns: list[Pattern] = []
+        head_sent = False
+        for lo in range(0, max(len(order), 1), stream.chunk):
+            sel = order[lo:lo + stream.chunk]
+            closures = reconstruct_closures(occ[sel], sup[sel], db_bits)
+            for j, c in zip(sel, closures):
+                if c in seen:
+                    continue
+                seen.add(c)
+                if pvals is None:
+                    p = q = float("nan")
+                else:
+                    p = float(pvals[j])
+                    q = min(1.0, p * k)
+                patterns.append(Pattern(
+                    items=c, support=int(sup[j]), pos_support=int(pos_sup[j]),
+                    pvalue=p, qvalue=q,
+                ))
+            if head_sent:
                 continue
-            seen.add(c)
-            if pvals is None:
-                p = q = float("nan")
-            else:
-                p = float(pvals[j])
-                q = min(1.0, p * k)
-            patterns.append(Pattern(
-                items=c, support=int(sup[j]), pos_support=int(pos_sup[j]),
-                pvalue=p, qvalue=q,
-            ))
-        if head_sent:
-            continue
+            patterns.sort(key=full_key)
+            nxt = lo + stream.chunk
+            if nxt >= len(order):
+                head_sent = True   # everything reconstructed: the head is final
+            elif (len(patterns) >= stream.head_k
+                  and partial(order[nxt]) > partial_p(patterns[stream.head_k - 1])):
+                head_sent = True
+            if head_sent:
+                stream.on_head(patterns[: stream.head_k])
+    with span("patterns"):
         patterns.sort(key=full_key)
-        nxt = lo + stream.chunk
-        if nxt >= len(order):
-            head_sent = True   # everything reconstructed: the head is final
-        elif (len(patterns) >= stream.head_k
-              and partial(order[nxt]) > partial_p(patterns[stream.head_k - 1])):
-            head_sent = True
-        if head_sent:
-            stream.on_head(patterns[: stream.head_k])
-    patterns.sort(key=full_key)
     return patterns

@@ -167,6 +167,14 @@ def main():
             "steals_got": res.stats["steals_got"].tolist(),
             "gives": res.stats["gives"].tolist(),
         }
+    elif spec["mode"] == "scopes":
+        # the superstep scopes of a fused23 query's compiled programs, and
+        # its answer, on this device count (tests/hlo_scopes.py)
+        from hlo_scopes import fused23_report
+
+        from repro.api import RuntimeConfig
+
+        out = fused23_report(db, labels, RuntimeConfig.from_engine_config(cfg))
     elif spec["mode"] == "trace_parity":
         # the same pass traced vs untraced on this device count: results
         # must be bit-identical, and the decoded trace must reconcile with

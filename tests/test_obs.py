@@ -398,6 +398,162 @@ def test_span_tracer_jax_profiler_bridge():
     assert len(tracer.events()) == 1
 
 
+def test_span_ids_parents_and_one_request_per_run():
+    """Every span of one `MinerSession.run` shares a request id; ids are
+    unique and each parent is the enclosing span of the same query."""
+    from repro.api import (ClosedFrequentQuery, Dataset, MinerSession,
+                           SignificantPatternQuery)
+
+    db, labels, _ = _problem(seed=2)
+    ds = Dataset.from_dense(db, labels, name="obs")
+    session = MinerSession()
+    session.run(ds, SignificantPatternQuery(alpha=0.05, pipeline="fused23"))
+    session.run(ds, SignificantPatternQuery(alpha=0.05, pipeline="three_phase"))
+    session.run(ds, ClosedFrequentQuery(min_sup=5))
+    events = session.tracer.events()
+    assert validate_chrome_trace(session.tracer.to_chrome_trace()) == len(events)
+    by_id = {e["id"]: e for e in events}
+    assert len(by_id) == len(events)
+    requests = sorted({e["request"] for e in events})
+    assert requests == [1, 2, 3]
+    for rid in requests:
+        mine = [e for e in events if e["request"] == rid]
+        roots = [e for e in mine if e["parent"] is None]
+        assert [r["name"] for r in roots] == [mine[-1]["name"]]
+        assert roots[0]["name"].startswith("query:")
+        for e in mine:
+            if e["parent"] is None:
+                continue
+            up = by_id[e["parent"]]
+            assert up["request"] == rid
+            assert up["ts"] <= e["ts"] <= e["ts"] + e["dur"] <= up["ts"] + up["dur"] + 1e-3
+    parent = {e["id"]: by_id[e["parent"]]["name"] for e in events
+              if e["parent"] is not None}
+    leaves = {(e["name"], parent[e["id"]]) for e in events if e["id"] in parent}
+    assert ("readback", "postprocess") in leaves
+    for step in ("closure", "patterns"):
+        assert (step, "reconstruct") in leaves
+    assert ("pvalues", "reconstruct") in leaves  # significance queries only
+    corrections = [e for e in events if e["name"] == "correction"]
+    assert [e["request"] for e in corrections] == [1, 2, 2]  # three_phase: k, root
+    assert {parent[e["id"]] for e in corrections} == {"query:SignificantPatternQuery"}
+
+
+def test_span_retention_stays_at_the_cap():
+    from repro.obs.span import SPAN_CAP
+
+    tracer = SpanTracer()
+    n = 100_000
+    assert n > SPAN_CAP
+    with tracer.request():
+        for i in range(n):
+            with tracer.span("s", i=i):
+                pass
+    events = tracer.events()
+    assert len(events) == SPAN_CAP  # the newest, oldest first
+    assert [e["args"]["i"] for e in events] == list(range(n - SPAN_CAP, n))
+    assert events[-1]["id"] == n and {e["request"] for e in events} == {1}
+
+
+def test_span_exception_keeps_structure():
+    tracer = SpanTracer()
+    with tracer.request() as rid:
+        with tracer.span("outer"):
+            with pytest.raises(ValueError):
+                with tracer.span("inner", step=1):
+                    raise ValueError("x")
+            with tracer.span("after"):
+                pass
+    inner, after, outer = tracer.events()
+    assert inner["args"] == {"step": 1, "error": "ValueError"}
+    assert inner["parent"] == after["parent"] == outer["id"]
+    assert outer["parent"] is None and "args" not in outer
+    assert inner["request"] == after["request"] == outer["request"] == rid
+    with tracer.span("outside"):
+        pass
+    last = tracer.events()[-1]
+    assert last["parent"] is None and last["request"] is None
+
+
+def test_bridged_span_names_stay_bare():
+    """The profiler sees the span's name alone: readers match spans by exact
+    name, and an encoded name (`closure#n=3#`) would not match."""
+    tracer = SpanTracer(jax_profiler=True)
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    tracer._annotation = Recorder
+    with tracer.span("closure", n_records=3):
+        with tracer.span("patterns"):
+            pass
+    assert seen == [("closure", {}), ("patterns", {})]
+    assert tracer.events()[-1]["args"] == {"n_records": 3}
+
+
+# -------------------------------------------------------------- device scopes
+_SCOPE_SPEC = dict(n_items=24, n_transactions=60, density=0.15, n_pos=20,
+                   n_planted=2, seed=0)
+
+
+def _scope_runtime():
+    from repro.api import RuntimeConfig
+
+    return RuntimeConfig.from_engine_config(_cfg())
+
+
+@pytest.fixture(scope="module")
+def scope_reports():
+    """A fused23 query on one device here and on four simulated devices in
+    a subprocess (pytest's jax already holds one device)."""
+    from hlo_scopes import fused23_report
+
+    from repro.data.synthetic import SyntheticSpec, generate
+
+    db, labels, _ = generate(SyntheticSpec(name="sub", **_SCOPE_SPEC))
+    one = fused23_report(db, labels, _scope_runtime())
+    four = run_subproc(dict(_SCOPE_SPEC, mode="scopes", n_devices=4,
+                            expand_batch=8, stack_cap=2048, steal_max=32,
+                            push_cap=128))
+    return {1: one, 4: four}
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("mode", ["lamp1", "count2d"])
+def test_superstep_loop_names_its_scopes(scope_reports, mode, n_devices):
+    """Every working instruction of the superstep loop names expand, steal or
+    sync in its op_name (bar jnp.cumsum's out-of-line reduce-windows, which
+    JAX lowers without the caller's name stack), and the answer on four
+    devices is bit-identical to one device's."""
+    got = scope_reports[n_devices]["programs"][mode]
+    assert got["unscoped"] == []
+    assert got["scopes"] == ["expand", "steal", "sync"]  # no ring: no trace
+    one, many = scope_reports[1], scope_reports[n_devices]
+    for key in ("lambda", "k", "n_significant", "hist2d", "patterns"):
+        assert many[key] == one[key], key
+
+
+def test_trace_scope_only_with_the_ring():
+    from hlo_scopes import loop_instructions, scope_of
+
+    from repro.api import Dataset, MinerSession, RuntimeConfig
+
+    db, labels, _ = _problem(seed=0)
+    session = MinerSession(runtime=RuntimeConfig(trace_period=1, trace_cap=64))
+    rep = session.run_phase(Dataset.from_dense(db, labels), "count", min_sup=3)
+    text = rep.compiled.as_text()
+    assert {scope_of(op) for _, _, op in loop_instructions(text)} >= {
+        "expand", "steal", "sync", "trace"}
+
+
 # -------------------------------------------------------------- metrics layer
 def test_metrics_exposition_format():
     reg = MetricsRegistry()
