@@ -672,11 +672,14 @@ class MinerSession:
 
     def _build_results(self, dataset: Dataset, phase_out: MineOutput, *,
                        alpha, min_sup, k, delta, filter_host,
-                       statistic: str | None = "fisher", records=None):
+                       statistic: str | None = "fisher", records=None,
+                       cell_pvalues=None):
         """Emitted records of one phase output -> ResultSet (repro.results).
 
         `records=(occ, sup, pos_sup)` overrides the phase output's emitted
         arrays (used to append host-side records, e.g. the root closed set).
+        `cell_pvalues=(sups, pos_sups, pvalues)` are cells already tested
+        with `statistic`; their records are not tested again.
         """
         from repro.results import build_result_set
 
@@ -696,7 +699,7 @@ class MinerSession:
                 min_sup=min_sup, correction_factor=k, delta=delta,
                 filter_host=filter_host, dropped=phase_out.emit_dropped,
                 item_names=dataset.item_names, statistic=statistic,
-                stream=stream, tracer=self.tracer,
+                stream=stream, tracer=self.tracer, cell_pvalues=cell_pvalues,
             )
 
     def _root_record(self, dataset: Dataset, phase_out: MineOutput,
@@ -891,10 +894,14 @@ def _pipeline_fused23(session: MinerSession, dataset: Dataset,
         # root appended iff significant — the 2-D histogram counted it then
         records = session._root_record(dataset, ph2.output, statistic, delta,
                                        min_sup)
-    # records were emitted at the alpha superset level; exact-filter at delta
+    # records were emitted at the alpha superset level; exact-filter at delta.
+    # Every emitted record is a counted node with sup >= min_sup, so its cell
+    # is one just tested: the filter reads those P-values, and list and count
+    # decide on the same numbers
     results = session._build_results(
         dataset, ph2.output, alpha=alpha, min_sup=min_sup, k=k, delta=delta,
         filter_host=True, statistic=statistic, records=records,
+        cell_pvalues=(xs, ns, pv),
     )
     return MineReport(
         dataset=dataset.name,

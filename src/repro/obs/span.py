@@ -89,7 +89,10 @@ class SpanTracer:
 
     @contextmanager
     def span(self, name: str, **args):
-        """Time a nested region; extra kwargs land in the event's args."""
+        """Time a nested region; extra kwargs land in the event's args.
+
+        Yields that args dict: keys set on it before the region ends (a
+        count known only inside it) land in the event too."""
         sid = next(self._ids)
         stack = self._open()
         parent = stack[-1] if stack else None
@@ -100,7 +103,7 @@ class SpanTracer:
         t0 = self._now_us()
         error = None
         try:
-            yield self
+            yield args
         except BaseException as e:
             error = type(e).__name__
             raise

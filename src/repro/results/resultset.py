@@ -18,6 +18,11 @@ Two filtering regimes (DESIGN.md §4):
   * mode="count2d" records are the alpha-level superset — pass
     ``filter_host=True`` and the host keeps exactly those with exact
     P <= delta, reproducing the fused pipeline's histogram-derived count.
+    The fused pipeline also passes ``cell_pvalues``, the (support,
+    pos_support) cells its correction already tested: a record's P-value
+    depends only on its cell, so each record reads its cell's P and only a
+    record whose cell is missing (the appended root, say) is tested
+    directly.  List and count then filter the very same numbers.
 
 Streaming (DESIGN.md §10): pass a `ResultStream` and the builder processes
 records in significance order — P-values need only (sup, pos_sup), so they
@@ -247,6 +252,7 @@ def build_result_set(
     statistic: str | None = "fisher",
     stream: ResultStream | None = None,
     tracer=None,
+    cell_pvalues: tuple[np.ndarray, np.ndarray, np.ndarray] | None = None,
 ) -> ResultSet:
     """Emitted records -> deduped, exactly-(re)tested, sorted ResultSet.
 
@@ -257,9 +263,14 @@ def build_result_set(
     a callback mid-build (see `ResultStream`); the returned ResultSet is
     identical either way.  `tracer` (a `repro.obs.SpanTracer`) times the
     build's three steps as spans `closure` (reconstruction and dedup),
-    `pvalues` (the host test and the delta filter) and `patterns` (building
+    `pvalues` (the records' P-values and the delta filter) and `patterns` (building
     and sorting the `Pattern`s); streaming interleaves closures with
-    pattern building, so there `closure` holds both.
+    pattern building, so there `closure` holds both.  `cell_pvalues`
+    ``(sups, pos_sups, pvalues)`` are cells the caller has already tested
+    with `statistic` (fused23 passes its correction's): a record in one of
+    them takes that cell's P-value, any other record is tested directly.
+    The `pvalues` span records ``n_records`` and ``n_tested``, the records
+    tested directly.
     """
     span = tracer.span if tracer is not None else _no_span
     occ = np.asarray(occ, dtype=np.uint32).reshape(-1, db_bits.shape[1])
@@ -271,7 +282,7 @@ def build_result_set(
         patterns = _build_patterns_streaming(
             occ, sup, pos_sup, db_bits, n=n, n_pos=n_pos, k=k, delta=delta,
             filter_host=filter_host, statistic=statistic, stream=stream,
-            span=span,
+            span=span, cell_pvalues=cell_pvalues,
         )
         return ResultSet(
             patterns=patterns,
@@ -291,8 +302,9 @@ def build_result_set(
         closures, sup, pos_sup = dedup_by_closure(closures, sup, pos_sup)
 
     if len(closures) and statistic is not None:
-        with span("pvalues"):
-            pvals = get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
+        with span("pvalues", n_records=len(sup)) as args:
+            pvals, args["n_tested"] = _record_pvalues(
+                statistic, sup, pos_sup, n, n_pos, cell_pvalues)
             keep = np.flatnonzero(pvals <= delta if filter_host
                                   else np.ones(len(closures), bool))
 
@@ -340,8 +352,32 @@ def build_result_set(
     )
 
 
-def _no_span(name: str):
-    return nullcontext()
+def _no_span(name: str, **args):
+    return nullcontext(args)
+
+
+def _record_pvalues(statistic, sup, pos_sup, n, n_pos, cells):
+    """Each record's P-value -> (pvalues, records tested directly).
+
+    A record whose (sup, pos_sup) cell is in `cells` takes the P-value the
+    caller already computed for that cell; the rest are tested here."""
+    hit = np.zeros(len(sup), bool)
+    pvals = np.empty(len(sup), np.float64)
+    if cells is not None and len(cells[0]):
+        cell_sup, cell_pos, cell_pv = (np.asarray(a).reshape(-1) for a in cells)
+        # one key per cell, as pos_sup <= n_pos
+        keys = cell_sup.astype(np.int64) * (n_pos + 1) + cell_pos
+        order = np.argsort(keys)
+        want = sup * (n_pos + 1) + pos_sup
+        at = order[np.minimum(np.searchsorted(keys, want, sorter=order),
+                              len(keys) - 1)]
+        hit = keys[at] == want
+        pvals[hit] = cell_pv[at[hit]]
+    miss = ~hit
+    if miss.any():
+        pvals[miss] = get_statistic(statistic).pvalue(
+            sup[miss], pos_sup[miss], n, n_pos)
+    return pvals, int(miss.sum())
 
 
 def _sort_key(statistic: str | None):
@@ -354,7 +390,7 @@ def _sort_key(statistic: str | None):
 
 def _build_patterns_streaming(
     occ, sup, pos_sup, db_bits, *, n, n_pos, k, delta, filter_host,
-    statistic, stream: ResultStream, span=_no_span,
+    statistic, stream: ResultStream, span=_no_span, cell_pvalues=None,
 ) -> list[Pattern]:
     """Reconstruct records in significance order, stream the head early.
 
@@ -377,9 +413,9 @@ def _build_patterns_streaming(
         partial = lambda j: (-int(sup[j]),)                    # noqa: E731
         partial_p = lambda p: (-p.support,)                    # noqa: E731
     else:
-        with span("pvalues"):
-            pvals = (get_statistic(statistic).pvalue(sup, pos_sup, n, n_pos)
-                     if n_rec else np.zeros(0))
+        with span("pvalues", n_records=n_rec) as args:
+            pvals, args["n_tested"] = _record_pvalues(
+                statistic, sup, pos_sup, n, n_pos, cell_pvalues)
             idx = (np.flatnonzero(pvals <= delta) if filter_host
                    else np.arange(n_rec))
             order = (idx[np.lexsort((idx, -sup[idx], pvals[idx]))]

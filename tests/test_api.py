@@ -236,6 +236,23 @@ def test_fused23_session_matches_three_phase():
     assert session.cache_info().misses == 4
 
 
+@pytest.mark.parametrize("statistic", ["fisher", "chi2"])
+def test_fused23_filter_reads_the_correction_cells(statistic):
+    """fused23's host filter reads the P-values the correction computed for
+    the 2-D histogram's cells: the list and the count agree, and at most the
+    root record is tested again."""
+    db, labels, _ = small_problem(seed=4)
+    session = MinerSession(runtime=RUNTIME)
+    rep = session.run(Dataset.from_dense(db, labels),
+                      SignificantPatternQuery(statistic=statistic,
+                                              pipeline="fused23"))
+    assert rep.results.complete and len(rep.results) > 0
+    assert rep.n_significant == len(rep.results)
+    (span,) = [e for e in session.tracer.events() if e["name"] == "pvalues"]
+    assert span["args"]["n_records"] >= len(rep.results)
+    assert span["args"]["n_tested"] <= 1
+
+
 def test_unknown_pipeline_raises():
     db, labels, _ = small_problem()
     session = MinerSession(runtime=RUNTIME)

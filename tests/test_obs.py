@@ -382,6 +382,14 @@ def test_span_tracer_nesting_and_export(tmp_path):
     assert tracer.events() == []
 
 
+def test_span_sets_args_before_it_closes():
+    tracer = SpanTracer()
+    with tracer.span("pvalues", n_records=3) as args:
+        args["n_tested"] = 1
+    (event,) = tracer.events()
+    assert event["args"] == {"n_records": 3, "n_tested": 1}
+
+
 def test_span_tracer_records_on_exception():
     tracer = SpanTracer()
     with pytest.raises(RuntimeError):

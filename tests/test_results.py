@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import jax
@@ -20,7 +21,18 @@ from repro.core.engine import EngineConfig, lamp_distributed, mine
 from repro.core.fisher import fisher_pvalue
 from repro.core.lamp import lamp
 from repro.data.synthetic import SyntheticSpec, generate
-from repro.results import Pattern, ResultSet, score_planted
+from repro.core.bitmap import full_occ, pack_db, supports_np
+from repro.obs import SpanTracer
+from repro.results import (
+    Pattern,
+    ResultSet,
+    ResultStream,
+    build_result_set,
+    dedup_by_closure,
+    reconstruct_closures,
+    score_planted,
+)
+from repro.stats import get_statistic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -173,6 +185,101 @@ def test_emission_overflow_warns_counts_and_flags_incomplete():
     assert len(rs) < base["n_significant"]
     base_keys = {_pattern_key(p) for p in base["results"]}
     assert {_pattern_key(p) for p in rs} <= base_keys
+
+
+# ------------------------------------------------- P-values from tested cells
+def _host_records(seed=0):
+    """Records of every item and item pair of a small problem, one per
+    closure, with the problem's packed bits: (db_bits, occ, sup, pos_sup,
+    n, n_pos)."""
+    db, labels, _ = small_problem(seed=seed)
+    n, m = db.shape
+    db_bits = pack_db(db)
+    pos_bits = pack_db(labels.astype(bool)[:, None])
+    pairs = [(i, i) for i in range(m)] + [
+        (i, j) for i in range(m) for j in range(i + 1, m)]
+    occ = np.stack([db_bits[i] & db_bits[j] for i, j in pairs])
+    sup = supports_np(occ, full_occ(n)[None])[:, 0].astype(np.int64)
+    occ, sup = occ[sup > 0], sup[sup > 0]
+    pos_sup = supports_np(occ, pos_bits)[:, 0].astype(np.int64)
+    closures = reconstruct_closures(occ, sup, db_bits)
+    first = {}
+    for i, c in enumerate(closures):
+        first.setdefault(c, i)
+    keep = np.array(sorted(first.values()))
+    return db_bits, occ[keep], sup[keep], pos_sup[keep], n, int(labels.sum())
+
+
+def _cells(statistic, sup, pos_sup, n, n_pos):
+    """The distinct (sup, pos_sup) cells, tested in one batch, in row-major
+    order as the fused pipeline's correction tests them."""
+    key = np.unique(sup * (n_pos + 1) + pos_sup)
+    xs, ns = key // (n_pos + 1), key % (n_pos + 1)
+    return xs, ns, get_statistic(statistic).pvalue(xs, ns, n, n_pos)
+
+
+def _build(records, statistic, delta, streaming, cells):
+    """One filtered build -> (ResultSet, the `pvalues` span's args)."""
+    db_bits, occ, sup, pos_sup, n, n_pos = records
+    tracer = SpanTracer()
+    stream = (ResultStream(head_k=3, on_head=lambda head: None, chunk=16)
+              if streaming else None)
+    rs = build_result_set(
+        occ, sup, pos_sup, db_bits, n=n, n_pos=n_pos, alpha=0.05, min_sup=1,
+        correction_factor=len(sup), delta=delta, filter_host=True,
+        statistic=statistic, stream=stream, tracer=tracer, cell_pvalues=cells,
+    )
+    (span,) = [e for e in tracer.events() if e["name"] == "pvalues"]
+    return rs, span["args"]
+
+
+def _assert_same_results(a, b):
+    assert len(a) > 0
+    assert [_pattern_key(p) for p in a] == [_pattern_key(p) for p in b]
+    pa = np.array([p.pvalue for p in a])
+    pb = np.array([p.pvalue for p in b])
+    np.testing.assert_allclose(pa, pb, rtol=1e-12, atol=0)
+
+
+@pytest.mark.parametrize("streaming", [False, True], ids=["batch", "stream"])
+@pytest.mark.parametrize("statistic", ["fisher", "chi2"])
+def test_cell_pvalues_give_the_direct_test_results(statistic, streaming):
+    """Records read their P-values from the tested cells: the same patterns
+    in the same order as testing every record, and nothing tested again."""
+    records = _host_records()
+    _, _, sup, pos_sup, n, n_pos = records
+    cells = _cells(statistic, sup, pos_sup, n, n_pos)
+    delta = float(np.quantile(cells[2], 0.5))
+    direct, direct_args = _build(records, statistic, delta, streaming, None)
+    looked_up, args = _build(records, statistic, delta, streaming, cells)
+    _assert_same_results(looked_up, direct)
+    assert len(looked_up) < len(sup)  # the delta filter dropped some
+    assert args == {"n_records": len(sup), "n_tested": 0}
+    assert direct_args == {"n_records": len(sup), "n_tested": len(sup)}
+
+
+@pytest.mark.parametrize("missing", ["dropped_cell", "root"])
+@pytest.mark.parametrize("streaming", [False, True], ids=["batch", "stream"])
+def test_records_without_a_tested_cell_are_tested_directly(missing, streaming):
+    statistic = "chi2"  # the root's P (0.5) passes a delta of 0.5
+    db_bits, occ, sup, pos_sup, n, n_pos = _host_records()
+    xs, ns, pv = _cells(statistic, sup, pos_sup, n, n_pos)
+    if missing == "dropped_cell":
+        gone = np.flatnonzero((xs == sup[0]) & (ns == pos_sup[0]))
+        xs, ns, pv = (np.delete(a, gone) for a in (xs, ns, pv))
+        n_missing = int(np.sum((sup == sup[0]) & (pos_sup == pos_sup[0])))
+    else:
+        assert not np.any((xs == n) & (ns == n_pos))
+        occ = np.concatenate([occ, full_occ(n)[None]])
+        sup, pos_sup = np.append(sup, n), np.append(pos_sup, n_pos)
+        n_missing = 1
+    records = (db_bits, occ, sup, pos_sup, n, n_pos)
+    direct, _ = _build(records, statistic, 0.5, streaming, None)
+    looked_up, args = _build(records, statistic, 0.5, streaming, (xs, ns, pv))
+    _assert_same_results(looked_up, direct)
+    assert args == {"n_records": len(sup), "n_tested": n_missing}
+    if missing == "root":
+        assert any(p.support == n for p in looked_up)
 
 
 # ------------------------------------------------------------------ scoring
